@@ -10,9 +10,11 @@ used to carry its own copy of this dance; they all route through here now:
   flag to ``XLA_FLAGS`` unless one is already inherited (so an outer harness
   can still pin it).  Call it from ``main()`` before any jax-importing work.
 * :func:`subprocess_env` — the sweep cell: an environ copy with the count
-  pinned to exactly ``n`` (*replacing* any inherited flag).  Weak-scaling
-  sweeps need a fresh process per device count, and the child must not
-  inherit the parent's mesh size.
+  pinned to exactly ``n`` (*replacing* any inherited flag) and the backend
+  pinned to the CPU.  Weak-scaling sweeps need a fresh process per device
+  count, and the child must not inherit the parent's mesh size.  Such a
+  cell is a host-device rehearsal: on a TPU machine the parent holds the
+  chip, and a child that asked for it would fail or hang.
 """
 from __future__ import annotations
 
@@ -38,11 +40,13 @@ def ensure_host_devices(n: Optional[int] = None) -> int:
 
 
 def subprocess_env(n: int) -> Dict[str, str]:
-    """An ``os.environ`` copy whose XLA device count is exactly ``n``: any
-    inherited ``--xla_force_host_platform_device_count`` is stripped first,
-    so a sweep's child processes get the cell's mesh size, not the
-    parent's."""
+    """An ``os.environ`` copy for a CPU-only child with exactly ``n`` host
+    devices: any inherited ``--xla_force_host_platform_device_count`` is
+    stripped first, so a sweep's child processes get the cell's mesh size,
+    not the parent's, and ``JAX_PLATFORMS=cpu`` keeps them off any
+    accelerator the parent holds."""
     env = dict(os.environ)
     flags = re.sub(_FLAG + r"=\d+", "", env.get("XLA_FLAGS", "")).strip()
     env["XLA_FLAGS"] = (flags + " " if flags else "") + f"{_FLAG}={n}"
+    env["JAX_PLATFORMS"] = "cpu"
     return env

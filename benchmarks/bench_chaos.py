@@ -167,6 +167,8 @@ def main() -> None:
                     help="CI-sized run (fewer streams/windows)")
     ap.add_argument("--out", default="BENCH_chaos.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     res = run(args.smoke)
     with open(args.out, "w") as f:

@@ -191,6 +191,8 @@ def main() -> None:
     # before the first lazy jax import below: give the fleet a mesh
     from benchmarks._device_env import ensure_host_devices
     ensure_host_devices(args.devices)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     res = run(args.smoke)
     with open(args.out, "w") as f:

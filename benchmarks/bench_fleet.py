@@ -42,9 +42,12 @@ This benchmark pins the fleet properties the executors rely on:
   CI-gated.
 
 * ``weak_scaling`` — the thousand-stream sweep: wall/stream and dispatch
-  overhead at S x device-count cells, each cell a fresh subprocess with its
-  XLA device count pinned (``benchmarks._device_env.subprocess_env``; the
-  count is fixed at backend init, so a sweep cannot run in-process).  Every
+  overhead at S x device-count cells, each cell a fresh CPU-pinned
+  subprocess with its XLA host-device count pinned
+  (``benchmarks._device_env.subprocess_env``; the count is fixed at backend
+  init, so a sweep cannot run in-process).  The cells are host-device
+  rehearsals of the stream mesh, never chip measurements: a child never
+  asks for the accelerator the parent process holds.  Every
   cell must hold one dispatch per window and zero retraces after its first
   window; sampled streams must match the unsharded sequential path to 1e-6
   and agree across device counts; and wall/stream at the largest S must
@@ -70,6 +73,7 @@ import time
 from typing import Dict, List, Optional
 
 from benchmarks._device_env import ensure_host_devices, subprocess_env
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _fleet_streams(n_streams: int, n_windows: int, records_per_window: int,
@@ -360,17 +364,18 @@ def _bench_batch_refresh(cfg, bp, n_streams: int, n_windows: int,
 
 
 # ---------------------------------------------------------------------------
-# Weak scaling: wall/stream and dispatch overhead, S x devices, one
-# subprocess per cell (XLA fixes its device count at backend init)
+# Weak scaling: wall/stream and dispatch overhead, S x host devices, one
+# CPU-pinned subprocess per cell (XLA fixes its device count at backend
+# init) — a host-device rehearsal of the stream mesh
 # ---------------------------------------------------------------------------
 
 
 def _weak_cell(spec: Dict) -> Dict:
-    """One (n_streams, devices) cell, run inside a child process whose XLA
-    device count the parent pinned via ``subprocess_env``: W windows of the
-    one-dispatch fleet fit over synthetic per-stream windows (deterministic
-    per (seed, stream, window) — identical data in every cell), plus the
-    two per-cell correctness probes the sweep gates on:
+    """One (n_streams, devices) cell, run inside a CPU-pinned child process
+    whose host-device count the parent pinned via ``subprocess_env``: W
+    windows of the one-dispatch fleet fit over synthetic per-stream windows
+    (deterministic per (seed, stream, window) — identical data in every
+    cell), plus the two per-cell correctness probes the sweep gates on:
 
     * parity vs the unsharded path — sampled streams (first/middle/last)
       refit sequentially through ``CompiledForecaster`` with the same keys;
@@ -432,6 +437,7 @@ def _weak_cell(spec: Dict) -> Dict:
     sb = bucket_streams(S)
     return {
         "n_streams": S,
+        "platform": jax.devices()[0].platform,
         "devices": jax.device_count(),
         "mesh_devices": len(stream_mesh_devices(sb)),
         "stream_bucket": sb,
@@ -450,8 +456,8 @@ def _weak_cell(spec: Dict) -> Dict:
 
 
 def _run_weak_cell(spec: Dict, n_devices: int) -> Dict:
-    """Launch one sweep cell in a fresh process with its device count
-    pinned, and parse the cell JSON it prints."""
+    """Launch one sweep cell in a fresh CPU-pinned process with its
+    host-device count pinned, and parse the cell JSON it prints."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = subprocess_env(n_devices)
     env["PYTHONPATH"] = os.pathsep.join(
@@ -642,8 +648,8 @@ def report(res: Dict) -> str:
         f"{br['hybrid_rmse_refresh']:.4f} vs "
         f"{br['hybrid_rmse_baseline']:.4f}",
         "",
-        "# weak scaling (one subprocess per cell; wall/stream, steady "
-        "median)",
+        "# weak scaling, host-device rehearsal (one CPU-pinned subprocess "
+        "per cell; wall/stream, steady median)",
     ]
     ws = res["weak_scaling"]
     lines.append(f"{'streams':<10}" + "".join(
@@ -688,12 +694,14 @@ def main() -> None:
     if args.weak_cell is not None:
         # child of the weak-scaling sweep: the parent pinned the device
         # count in our environment; print the cell JSON and nothing else
+        enable_compile_cache()
         print(json.dumps(_weak_cell(json.loads(args.weak_cell))))
         return
 
     # must land before the first (lazy) jax import anywhere below: expose
     # the cores as XLA devices so the fleet's stream axis has a mesh
     ensure_host_devices(args.devices)
+    enable_compile_cache()
 
     if args.smoke:
         defaults = dict(n_streams=4, n_windows=4, epochs=3,

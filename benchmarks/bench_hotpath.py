@@ -307,6 +307,8 @@ def main() -> None:
     p.add_argument("--records", type=int, default=None)
     p.add_argument("--out", default="BENCH_hotpath.json")
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         defaults = dict(n_windows=4, epochs=3, records_per_window=120,

@@ -219,6 +219,8 @@ def main() -> None:
     # before the first lazy jax import below: give the fleet a mesh
     from benchmarks._device_env import ensure_host_devices
     ensure_host_devices(args.devices)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         defaults = dict(n_streams=3, n_windows=3, records_per_window=120,

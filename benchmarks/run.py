@@ -21,6 +21,8 @@ def main() -> None:
     p.add_argument("--only", default=None,
                    help="comma list: table3,fig7,fig8,roofline")
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = not args.full
     only = set(args.only.split(",")) if args.only else None
     csv_rows = [("name", "us_per_call", "derived")]

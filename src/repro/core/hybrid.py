@@ -84,19 +84,20 @@ def lstm_forecaster(cfg: ModelConfig, *, epochs: int, batch_size: int,
 
 
 def lstm_fleet_forecaster(cfg: ModelConfig, *, epochs: int, batch_size: int,
-                          lr: float = 1e-3):
+                          lr: float = 1e-3, devices=None):
     """The paper's LSTM speed layer lifted to a fleet of streams: a
     ``repro.training.compiled.FleetForecaster`` that trains every stream's
     speed model in one vmapped dispatch per window (and satisfies the
     single-stream ``Forecaster`` protocol by delegating to its wrapped
-    ``CompiledForecaster``)."""
+    ``CompiledForecaster``).  ``devices`` bounds the stream mesh (default:
+    every local device)."""
     from repro.models import lstm as lstm_mod
     from repro.training.compiled import FleetForecaster
 
     model = get_model(cfg)
     return FleetForecaster(
         model, epochs=epochs, batch_size=batch_size, lr=lr,
-        predict_fn=lambda p, x: lstm_mod.predict(cfg, p, x))
+        predict_fn=lambda p, x: lstm_mod.predict(cfg, p, x), devices=devices)
 
 
 @dataclass

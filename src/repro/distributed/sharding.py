@@ -41,7 +41,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical name -> preferred mesh axis names (in priority order, used jointly
 # when all divide, else greedily)
@@ -83,10 +83,20 @@ def _ctx():
     return getattr(_local, "ctx", None)
 
 
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The rules here are GSPMD
+    sharding constraints, which may name only Auto axes — and
+    ``jax.make_mesh`` makes Explicit ones."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 @contextmanager
 def use_mesh_rules(mesh: Mesh, rules: Optional[AxisRules] = None):
     prev = _ctx()
-    _local.ctx = (mesh, rules or AxisRules())
+    _local.ctx = (_auto_axes(mesh), rules or AxisRules())
     try:
         yield
     finally:

@@ -7,12 +7,16 @@ quarters (vs f32) the weight HBM traffic — the dominant cost of small-batch
 edge inference — and applies the scale once per output column after the
 K-loop instead of once per weight.
 
-Tiling: (block_m, block_n) output tiles on a parallel grid; the K dimension
-streams through VMEM in block_k slices inside a fori_loop with an f32
-accumulator.  int8 weights are converted to f32 in VREGs right before the
-MXU dot (TPU int8 MXU paths need quantized activations too; weight-only
-quantization keeps activations f32/bf16, which is what the forecaster
-accuracy test pins).
+Tiling: (block_m, block_n) output tiles on the first two grid axes; the K
+dimension is the last grid axis, streamed through VMEM in block_k slices
+into an f32 accumulator scratch (zeroed at the first K step, scaled and
+written out at the last).  A dimension no larger than its block is taken
+whole — a full-dim block is always a legal Mosaic tile — so the
+forecaster's (K, N) weights (5x160, 40x160, 40x10, 10x1) run as one block
+each, with no padding.  The scale rides a 2-D (1, block_n) block.  int8
+weights are converted to f32 in VREGs right before the MXU dot (TPU int8
+MXU paths need quantized activations too; weight-only quantization keeps
+activations f32/bf16, which is what the forecaster accuracy test pins).
 """
 from __future__ import annotations
 
@@ -21,24 +25,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 
-def _kernel(x_ref, q_ref, s_ref, o_ref, *, block_k: int, n_k: int):
-    x = x_ref[...]  # (bm, K)
-    q = q_ref[...]  # (K, bn) int8
-    s = s_ref[...]  # (bn,) f32
+def _kernel(x_ref, q_ref, s_ref, o_ref, acc_ref):
+    k = pl.program_id(2)
 
-    def body(i, acc):
-        xs = jax.lax.dynamic_slice_in_dim(x, i * block_k, block_k, axis=1)
-        qs = jax.lax.dynamic_slice_in_dim(q, i * block_k, block_k, axis=0)
-        return acc + jnp.dot(
-            xs.astype(jnp.float32), qs.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
+    @pl.when(k == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc0 = jnp.zeros((x.shape[0], q.shape[1]), jnp.float32)
-    acc = jax.lax.fori_loop(0, n_k, body, acc0)
-    o_ref[...] = (acc * s[None, :]).astype(o_ref.dtype)
+    acc_ref[...] += jnp.dot(
+        x_ref[...].astype(jnp.float32), q_ref[...].astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _write():
+        o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -50,13 +56,19 @@ def int8_matmul(
     scale: jax.Array,  # (N,) f32
     *,
     block_m: int = 128,
-    block_n: int = 128,
+    block_n: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
+    """``x @ (q * scale)``.  ``interpret=None`` resolves via
+    ``repro.kernels.default_interpret()``: compiled Mosaic on a real TPU
+    backend, the Pallas interpreter elsewhere."""
+    interpret = default_interpret() if interpret is None else interpret
     M, K = x.shape
     K2, N = q.shape
-    assert K == K2 and scale.shape == (N,)
+    if K != K2 or scale.shape != (N,):
+        raise ValueError(f"int8_matmul shapes disagree: x {x.shape}, "
+                         f"q {q.shape}, scale {scale.shape}")
     bm, bn = min(block_m, M), min(block_n, N)
     bk = min(block_k, K)
     # pad every dim to its block multiple (zero padding is exact here)
@@ -68,17 +80,19 @@ def int8_matmul(
     if pn:
         scale = jnp.pad(scale, (0, pn))
     Mp, Kp, Np = M + pm, K + pk, N + pn
-    grid = (Mp // bm, Np // bn)
     out = pl.pallas_call(
-        functools.partial(_kernel, block_k=bk, n_k=Kp // bk),
-        grid=grid,
+        _kernel,
+        grid=(Mp // bm, Np // bn, Kp // bk),
         in_specs=[
-            pl.BlockSpec((bm, Kp), lambda i, j: (i, 0)),
-            pl.BlockSpec((Kp, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, q, scale)
+    )(x, q, scale.reshape(1, Np))
     return out[:M, :N]

@@ -42,12 +42,12 @@ def _lstm_sequence(x, wx, wh, b, interpret):
 
 def _lstm_sequence_fwd(x, wx, wh, b, interpret):
     """Differentiated forward: the residual-emitting kernel.  Residuals are
-    the post-activation gates and the full c/h sequences (all f32), so the
-    backward kernel reconstructs the recurrence without re-running any
-    matmul."""
+    the post-activation gates and the full c/h sequences (all f32,
+    time-major), so the backward kernel reconstructs the recurrence without
+    re-running any matmul."""
     gates, c_seq, h_seq = lstm_sequence_fwd_train(x, wx, wh, b,
                                                   interpret=interpret)
-    h = h_seq[:, -1].astype(x.dtype)
+    h = h_seq[-1].astype(x.dtype)
     return h, (x, gates, c_seq, h_seq, wx, wh, b)
 
 

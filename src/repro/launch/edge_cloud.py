@@ -15,6 +15,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _print_table(table, e2e=None) -> None:
     for m, row in table.items():
@@ -84,7 +86,8 @@ def build_real_pipeline(n_windows: int, fast: bool = True,
 
 def build_fleet_pipeline(n_streams: int, n_windows: int, fast: bool = True,
                          mode="dynamic", records_per_window: int = 250,
-                         scenario="gradual", verbose: bool = False):
+                         scenario="gradual", verbose: bool = False,
+                         devices=None):
     """The fleet analog of :func:`build_real_pipeline`: N correlated
     turbines (``streams.sources.turbine_fleet``), each scaled by its own
     history, all served by one shared pre-trained batch model; returns
@@ -92,7 +95,9 @@ def build_fleet_pipeline(n_streams: int, n_windows: int, fast: bool = True,
 
     ``scenario`` is one drift scenario name for the whole fleet or a
     per-stream list ({"none", "gradual", "abrupt"} each) — the chaos
-    suite's ``compound_drift`` mixes all three across one fleet."""
+    suite's ``compound_drift`` mixes all three across one fleet.
+    ``devices`` bounds the fleet's stream mesh (default: every local
+    device)."""
     import jax
     import numpy as np
 
@@ -123,7 +128,8 @@ def build_fleet_pipeline(n_streams: int, n_windows: int, fast: bool = True,
     if verbose:
         print(f"  done in {t_pre:.1f}s")
 
-    fleet_fc = lstm_fleet_forecaster(cfg, epochs=speed_epochs, batch_size=64)
+    fleet_fc = lstm_fleet_forecaster(cfg, epochs=speed_epochs, batch_size=64,
+                                     devices=devices)
     stages = FleetStages.build(fleet_fc, mode=mode)
     cost = CostModel(ingest_s=rpw / 7.0 * 0.45)
     return stages, bp, streams, cost
@@ -446,6 +452,7 @@ def main() -> None:
                         "draws a different (but equally reproducible) "
                         "fault schedule")
     args = p.parse_args()
+    enable_compile_cache()
 
     if args.chaos is not None:
         from repro.core.scenarios import SCENARIOS

@@ -152,24 +152,6 @@ def moe_onehot(cfg: ModelConfig, p: Params, x: jax.Array,
 # Path 2: shard_map expert parallelism (fine-grained MoE)
 # ---------------------------------------------------------------------------
 
-# jax >= 0.6 exposes shard_map at the top level with the replication check
-# renamed check_vma; 0.4.x only has jax.experimental.shard_map with check_rep
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
-
-def _axis_size(name: str) -> int:
-    # jax.lax.axis_size is also a >= 0.6 addition; psum of a literal 1 is
-    # constant-folded to the static axis size on older versions
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 def _local_ep_body(cfg: ModelConfig, model_axis: str, fsdp_axes, x, p):
     """Per-device body. x: (B_loc, S, d) local tokens (replicated over model).
@@ -192,7 +174,7 @@ def _local_ep_body(cfg: ModelConfig, model_axis: str, fsdp_axes, x, p):
     E = moe.n_experts
     E_loc = p["we_in"].shape[0]
     experts_sharded = E_loc < E
-    n_shards = _axis_size(model_axis)
+    n_shards = jax.lax.axis_size(model_axis)
     my_shard = jax.lax.axis_index(model_axis)
 
     # gather FSDP-sharded expert weights for this layer (ZeRO-3 gather)
@@ -295,12 +277,12 @@ def moe_shard_map(cfg: ModelConfig, p: Params, x: jax.Array
     out_specs = (batch_spec, P())
     pp = {kk: p[kk] for kk in ("router", "we_in", "we_out", "we_gate") if kk in p}
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda xx, params: _local_ep_body(cfg, model_axis, fsdp_axes, xx, params),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     out, aux = fn(x, pp)
     return out, aux

@@ -550,11 +550,15 @@ class FleetForecaster:
         lr: float = 1e-3,
         opt: Optional[Optimizer] = None,
         predict_fn: Optional[Callable[[Params, jax.Array], jax.Array]] = None,
+        devices: Optional[Sequence[Any]] = None,
     ):
         self.single = CompiledForecaster(
             model, epochs=epochs, batch_size=batch_size, lr=lr, opt=opt,
             predict_fn=predict_fn)
         self.model = model
+        # the devices the stream axis may shard over (default: all local
+        # devices); one device pins the fleet to it, unsharded
+        self.devices = list(devices) if devices is not None else None
         self.epochs = int(epochs)
         self.batch_size = int(batch_size)
         self.opt = self.single.opt
@@ -625,7 +629,7 @@ class FleetForecaster:
         independent, so sharding the stacked axis is pure data parallelism
         — bitwise the same per-stream numerics as the unsharded vmap."""
         if sb not in self._shardings:
-            self._shardings[sb] = stream_sharding(sb)
+            self._shardings[sb] = stream_sharding(sb, self.devices)
         return self._shardings[sb]
 
     def _put(self, a: np.ndarray, sb: int):
@@ -680,7 +684,10 @@ class FleetForecaster:
 
     # -- the cached fleet-fit executable ------------------------------------
 
-    def _fleet_fit_fn(self, sb: int, nb: int) -> Callable:
+    def fleet_fit_fn(self, sb: int, nb: int) -> Callable:
+        """The cached jitted fleet-fit executable for (stream bucket ``sb``,
+        shape bucket ``nb``) — what ``train_fleet`` dispatches; ``.lower()``
+        it to read its HLO."""
         cache_key = (sb, nb)
         fn = self._fleet_cache.get(cache_key)
         if fn is not None:
@@ -820,7 +827,7 @@ class FleetForecaster:
         carry = self._opt_carry.pop((sb, nb), None)
         if carry is None:
             carry = self._carry_init_fn(sb)(ik_d)
-        params_S, opt_S, losses_S = self._fleet_fit_fn(sb, nb)(
+        params_S, opt_S, losses_S = self.fleet_fit_fn(sb, nb)(
             carry, ik_d, pk_d,
             self._put(bufs["x"], sb), self._put(bufs["y"], sb),
             self._put(bufs["mask"], sb))
@@ -834,10 +841,11 @@ class FleetForecaster:
 
     # -- one-dispatch fleet inference ----------------------------------------
 
-    def _predict_fleet_fn(self, sb: int) -> Callable:
-        """The cached vmapped predict executable for stream bucket ``sb``
-        (jit's own cache handles the inference shape buckets; the traced
-        body counts real retraces per (sb, nb))."""
+    def predict_fleet_fn(self, sb: int) -> Callable:
+        """The cached vmapped predict executable for stream bucket ``sb`` —
+        what ``predict_fleet`` dispatches (jit's own cache handles the
+        inference shape buckets; the traced body counts real retraces per
+        (sb, nb))."""
         fn = self._predict_cache.get(sb)
         if fn is None:
             pf = self.single._predict_raw
@@ -932,7 +940,7 @@ class FleetForecaster:
             buf[j, ns[j]:] = 0  # only the padding tail, not the whole buffer
         buf[S:] = 0  # padded stream slots
         x_dev = self._put(buf, sb) if on_mesh else jnp.asarray(buf)
-        preds = self._predict_fleet_fn(sb)(stacked, x_dev)
+        preds = self.predict_fleet_fn(sb)(stacked, x_dev)
         self.predict_dispatches += 1
         preds = np.asarray(preds)
         return [preds[j, :ns[j]] for j in range(S)]

@@ -75,3 +75,47 @@ def test_build_step_specs_have_shardings():
     # decode step: token/pos/cache present
     assert specs["batch"]["token"].shape == (128, 1)
     assert specs["cache"]["k"].shape[2] == 32768
+
+
+@pytest.fixture
+def restore_cache_config():
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in prev.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch, restore_cache_config):
+    from repro.launch.compile_cache import CHECKOUT_ROOT, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = enable_compile_cache()
+    assert path == os.path.join(CHECKOUT_ROOT, ".jax_cache")
+    assert os.path.isfile(os.path.join(CHECKOUT_ROOT, "pyproject.toml"))
+    assert jax.config.jax_compilation_cache_dir == path
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_compile_cache_env_path_sets_no_other(monkeypatch, tmp_path,
+                                              restore_cache_config):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "outside"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert enable_compile_cache() == str(tmp_path / "env")
+    # the env var is JAX's own; the helper leaves the config path alone
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "outside")
+
+
+def test_sweep_child_env_is_cpu_pinned(monkeypatch):
+    from benchmarks._device_env import subprocess_env
+
+    monkeypatch.setenv("XLA_FLAGS",
+                       "--xla_force_host_platform_device_count=8 --foo")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = subprocess_env(4)
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["XLA_FLAGS"] == "--foo --xla_force_host_platform_device_count=4"
