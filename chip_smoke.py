@@ -24,8 +24,9 @@ features, 10,981 parameters), with data generated from fixed seeds:
 predict with the stream axis over four chips, against the same fleet on one
 chip in this process.
 
-Each phase prints one JSON line of what it measured: compile seconds (JAX's
-backend-compile events; a persistent-cache hit is timed as its read),
+Each phase prints one JSON line of what it measured: programs built and
+their seconds (the program's compile records, ``repro.tracing``; a load
+from the persistent cache is timed as its read),
 steady seconds timed to ``block_until_ready``, dispatches, retraces,
 requests, parity and ``peak_bytes_in_use``.  Each check raises on a miss,
 so any failure exits non-zero, as does a run where JAX finds no TPU.  The
@@ -97,36 +98,16 @@ def require_custom_call(hlo: str, what: str) -> None:
     check("tpu_custom_call" in hlo, f"{what}: no tpu_custom_call in its HLO")
 
 
-class CompileMeter:
-    """Backend compiles (seconds and count) and persistent-cache hits, from
-    JAX's monitoring events."""
+def compiled_since(t0: float) -> dict:
+    """Programs built since ``t0`` (``time.perf_counter()``) and their
+    seconds, from the program's ``compile:<function>`` records; a load from
+    the persistent cache counts, timed as its read."""
+    from repro import tracing
 
-    BACKEND = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        import jax
-
-        self.seconds, self.compiles, self.cache_hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == self.BACKEND:
-            self.seconds += duration
-            self.compiles += 1
-
-    def _event(self, event, **_):
-        if event == self.HIT:
-            self.cache_hits += 1
-
-    def mark(self):
-        return self.seconds, self.compiles, self.cache_hits
-
-    def since(self, mark) -> dict:
-        return {"compile_s": self.seconds - mark[0],
-                "compiles": self.compiles - mark[1],
-                "cache_hits": self.cache_hits - mark[2]}
+    recs = tracing.records(since=t0)
+    check(recs is not None, "the span ring dropped compile records")
+    recs = [r for r in recs if r.name.startswith("compile:")]
+    return {"compile_s": sum(r.dur for r in recs), "compiles": len(recs)}
 
 
 def peak_bytes(device) -> int | None:
@@ -190,18 +171,17 @@ def reference_predict(params, x):
     return lstm.predict(get_config("lstm-paper"), dequantize_tree(params), x)
 
 
-def fleet_phase(name: str, quantized: bool, chip, cpu, meter) -> dict:
+def fleet_phase(name: str, quantized: bool, chip, cpu) -> dict:
     import jax
     import numpy as np
 
     from repro.runtime import fleet_key_chains
     from repro.training.compiled import bucket_streams
 
-    mark = meter.mark()
     t0 = time.perf_counter()
     stages, streams, res = run_fleet(chip, quantized)
     cold_run_s = time.perf_counter() - t0
-    cold = meter.since(mark)
+    cold = compiled_since(t0)
     fc = stages.speed_training.forecaster
     ids = list(streams)
     fit_retraces = fc.retrace_count - len(fc.trace_counts())
@@ -260,11 +240,11 @@ def fleet_phase(name: str, quantized: bool, chip, cpu, meter) -> dict:
         fc.predict_fleet(params_seq, ticks[0])  # stacks the serving tree
         traces0 = (fc.retrace_count
                    + sum(fc.predict_trace_counts().values()))
-        steady_mark = meter.mark()
+        steady_mark = time.perf_counter()
         fit_s = [fc.train_fleet(d, [keys[sid][w] for sid in ids])[1]
                  for w, d in enumerate(datas)]
         tick_s = [timed(fc.predict_fleet, params_seq, xs)[1] for xs in ticks]
-        steady = meter.since(steady_mark)
+        steady = compiled_since(steady_mark)
         traces = (fc.retrace_count
                   + sum(fc.predict_trace_counts().values()) - traces0)
     check(traces == 0 and steady["compiles"] == 0,
@@ -309,7 +289,7 @@ def fleet_phase(name: str, quantized: bool, chip, cpu, meter) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def kernel_phase(chip, cpu, meter) -> dict:
+def kernel_phase(chip, cpu) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -332,7 +312,7 @@ def kernel_phase(chip, cpu, meter) -> dict:
 
     grad_fused, grad_ref = grads(lstm_sequence), grads(lstm_sequence_ref)
     ref_fwd = jax.jit(lstm_sequence_ref)
-    mark = meter.mark()
+    mark = time.perf_counter()
     with jax.default_device(chip):
         dev_args = jax.device_put(args, chip)
         dev_ct = jax.device_put(ct, chip)
@@ -342,7 +322,7 @@ def kernel_phase(chip, cpu, meter) -> dict:
         g_fused = grad_fused(*dev_args, dev_ct)
         g_ref = grad_ref(*dev_args, dev_ct)
         jax.block_until_ready((h_fused, h_scan, h_ref, g_fused, g_ref))
-        compile_ = meter.since(mark)
+        compile_ = compiled_since(mark)
         fwd_s = statistics.median(timed(lstm_sequence, *dev_args)[1]
                                   for _ in range(20))
         grad_s = statistics.median(timed(grad_fused, *dev_args, dev_ct)[1]
@@ -381,7 +361,7 @@ def kernel_phase(chip, cpu, meter) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def mesh_phase(devices, meter) -> dict:
+def mesh_phase(devices) -> dict:
     import jax
     import numpy as np
 
@@ -407,7 +387,7 @@ def mesh_phase(devices, meter) -> dict:
     for label, devs in (("chips4", devices), ("chip1", devices[:1])):
         ff = lstm_fleet_forecaster(cfg, epochs=10, batch_size=64,
                                    devices=devs)
-        mark = meter.mark()
+        mark = time.perf_counter()
         walls = []
         with jax.default_device(devs[0]):
             for w, d in enumerate(datas):
@@ -433,7 +413,7 @@ def mesh_phase(devices, meter) -> dict:
             "fit_dispatches_per_window": ff.train_dispatches / len(datas),
             "fit_retraces_after_first_window": (ff.retrace_count
                                                 - len(ff.trace_counts())),
-            "shard_rows": shard_rows, **meter.since(mark),
+            "shard_rows": shard_rows, **compiled_since(mark),
             "peak_bytes_in_use": [peak_bytes(d) for d in devs],
         }
 
@@ -489,20 +469,21 @@ def main() -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     cache = enable_compile_cache()
-    meter = CompileMeter()
+    # imported before any program builds, so each leaves its record
+    from repro import tracing  # noqa: F401
     chip = devices[0]
     print(json.dumps({"compile_cache": cache, "devices": len(devices),
                       "device_kind": chip.device_kind}), flush=True)
     if args.chips == 4:
         used = devices[:4]
-        print(json.dumps(mesh_phase(used, meter)), flush=True)
+        print(json.dumps(mesh_phase(used)), flush=True)
     else:
         used = [chip]
         cpu = jax.devices("cpu")[0]
         for name, quantized in (("A", False), ("B", True)):
-            print(json.dumps(fleet_phase(name, quantized, chip, cpu, meter)),
+            print(json.dumps(fleet_phase(name, quantized, chip, cpu)),
                   flush=True)
-        print(json.dumps(kernel_phase(chip, cpu, meter)), flush=True)
+        print(json.dumps(kernel_phase(chip, cpu)), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": chip.platform, "kind": chip.device_kind,
         "count": len(used)}}))

@@ -48,6 +48,7 @@ from repro.core.weighting import (
     dwa_scipy,
     static_weights,
 )
+from repro.tracing import span
 
 Params = Any
 
@@ -78,13 +79,15 @@ class Stage:
     def __call__(self, **inputs: Any) -> StageOutput:
         import jax
 
-        t0 = time.perf_counter()
-        values = self.compute(**inputs)
-        pending = [x for x in jax.tree_util.tree_leaves(values)
-                   if isinstance(x, jax.Array)]
-        if pending:
-            jax.block_until_ready(pending)
-        return StageOutput(values=values, wall_s=time.perf_counter() - t0)
+        # the wall is the span's own, so the ledger and a profiler trace
+        # time the stage on one clock
+        with span("stage." + self.name) as sp:
+            values = self.compute(**inputs)
+            pending = [x for x in jax.tree_util.tree_leaves(values)
+                       if isinstance(x, jax.Array)]
+            if pending:
+                jax.block_until_ready(pending)
+        return StageOutput(values=values, wall_s=sp.dur)
 
 
 class BatchInference(Stage):

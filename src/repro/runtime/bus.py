@@ -14,6 +14,8 @@ import itertools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.tracing import span
+
 
 class CapacityError(RuntimeError):
     """A module exceeded its site's memory budget (the paper's edge-centric
@@ -121,15 +123,17 @@ class EventKernel:
         self.at(self.now + dt, fn)
 
     def run(self, until: Optional[float] = None) -> float:
-        while self._q:
-            if until is not None and self._q[0][0] > until:
-                # peek, don't pop: re-pushing with a fresh sequence number
-                # would silently reorder same-timestamp events across a
-                # pause/resume — the chaos suite relies on exact replay
-                break
-            t, _, fn = heapq.heappop(self._q)
-            self.now = max(self.now, t)
-            fn()
+        with span("loop"):
+            while self._q:
+                if until is not None and self._q[0][0] > until:
+                    # peek, don't pop: re-pushing with a fresh sequence
+                    # number would silently reorder same-timestamp events
+                    # across a pause/resume — the chaos suite relies on
+                    # exact replay
+                    break
+                t, _, fn = heapq.heappop(self._q)
+                self.now = max(self.now, t)
+                fn()
         return self.now
 
 

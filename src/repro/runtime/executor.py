@@ -99,6 +99,7 @@ from repro.serving.query_plane import (
     latency_stats,
     open_loop_trace,
 )
+from repro.tracing import span
 
 Params = Any
 
@@ -1308,49 +1309,54 @@ class FleetBusExecutor(_BusRuntime):
                         pend: Dict[StreamId, Message]) -> None:
         # the window's arrived streams are at the inference site: one
         # aggregated vmapped dispatch, per-stream results fan back out
-        sids = [s for s in self.ids if s in pend]
-        if kind == "batch":
-            stage, topic = self.stages.batch_inference, T_BATCH
-            out = stage(fleet={
-                sid: dict(batch_params=self._bp[sid],
-                          x=pend[sid].payload["x"])
-                for sid in sids})["fleet"]
-        else:
-            stage, topic = self.stages.speed_inference, T_SPEED
-            out = stage(fleet={
-                sid: dict(speed_params=self._fleet.state(sid).speed_params,
-                          x=pend[sid].payload["x"],
-                          fallback_params=self._bp[sid])
-                for sid in sids})["fleet"]
-        wall = out[sids[0]].wall_s
-        module = "batch_inference" if kind == "batch" else "speed_inference"
+        with span("executor.dispatch_infer"):
+            sids = [s for s in self.ids if s in pend]
+            if kind == "batch":
+                stage, topic = self.stages.batch_inference, T_BATCH
+                out = stage(fleet={
+                    sid: dict(batch_params=self._bp[sid],
+                              x=pend[sid].payload["x"])
+                    for sid in sids})["fleet"]
+            else:
+                stage, topic = self.stages.speed_inference, T_SPEED
+                out = stage(fleet={
+                    sid: dict(
+                        speed_params=self._fleet.state(sid).speed_params,
+                        x=pend[sid].payload["x"],
+                        fallback_params=self._bp[sid])
+                    for sid in sids})["fleet"]
+            wall = out[sids[0]].wall_s
+            module = ("batch_inference" if kind == "batch"
+                      else "speed_inference")
 
-        # fan the per-stream results back out from each stream's *current*
-        # site: under elastic placement the one aggregated dispatch is
-        # unchanged (aggregation happens above placement), but occupancy and
-        # result publishing are accounted per placement group — each group
-        # carries the shared aggregate wall, the same convention the fleet
-        # stages use per stream.  A static run is a single group, identical
-        # to the pre-elastic path.
-        groups: Dict[str, List[StreamId]] = {}
-        for sid in sids:
-            groups.setdefault(self._module_site(module, sid), []).append(sid)
-        for site_name, gsids in groups.items():
-            comm = max(pend[s].deliver_time - pend[s].publish_time
-                       for s in gsids) + self.cost.ingest_s
+            # fan the per-stream results back out from each stream's
+            # *current* site: under elastic placement the one aggregated
+            # dispatch is unchanged (aggregation happens above placement),
+            # but occupancy and result publishing are accounted per
+            # placement group — each group carries the shared aggregate
+            # wall, the same convention the fleet stages use per stream.  A
+            # static run is a single group, identical to the pre-elastic
+            # path.
+            groups: Dict[str, List[StreamId]] = {}
+            for sid in sids:
+                groups.setdefault(self._module_site(module, sid),
+                                  []).append(sid)
+            for site_name, gsids in groups.items():
+                comm = max(pend[s].deliver_time - pend[s].publish_time
+                           for s in gsids) + self.cost.ingest_s
 
-            def publish_preds(gsids=gsids, site_name=site_name):
-                for sid in gsids:
-                    o = out[sid]
-                    self.bus.publish(
-                        stream_topic(topic, sid),
-                        {"stream": sid, "window": w, "kind": kind,
-                         "pred": o["pred"], "wall_s": o.wall_s,
-                         "fallback": o.values.get("fallback", False)},
-                        _nbytes(o["pred"]), site_name)
+                def publish_preds(gsids=gsids, site_name=site_name):
+                    for sid in gsids:
+                        o = out[sid]
+                        self.bus.publish(
+                            stream_topic(topic, sid),
+                            {"stream": sid, "window": w, "kind": kind,
+                             "pred": o["pred"], "wall_s": o.wall_s,
+                             "fallback": o.values.get("fallback", False)},
+                            _nbytes(o["pred"]), site_name)
 
-            self._schedule(module, wall, comm, publish_preds,
-                           site_name=site_name)
+                self._schedule(module, wall, comm, publish_preds,
+                               site_name=site_name)
 
     def _on_part(self, msg: Message) -> None:
         sid, w = msg.payload["stream"], msg.payload["window"]
@@ -1358,41 +1364,44 @@ class FleetBusExecutor(_BusRuntime):
         parts[msg.payload["kind"]] = msg
         if len(parts) < 2:
             return
-        st = self.stages.single
-        state = self._fleet.state(sid)
-        bmsg, smsg = parts["batch"], parts["speed"]
-        comm = max(m.deliver_time - m.publish_time for m in parts.values())
-        wsol = st.weight_solve(prev_preds=state.prev_preds,
-                               prev_y=state.prev_y)
-        t_w = (wsol.wall_s if st.weight_solve.is_dynamic
-               and state.prev_preds is not None else 0.0)
-        hc = st.hybrid_combine(
-            pred_speed=smsg.payload["pred"], pred_batch=bmsg.payload["pred"],
-            w_speed=wsol["w_speed"], w_batch=wsol["w_batch"])
-        y = self._ys[(sid, w)]
-        rec = WindowRecord(
-            window=w,
-            rmse_batch=rmse(y, bmsg.payload["pred"]),
-            rmse_speed=rmse(y, smsg.payload["pred"]),
-            rmse_hybrid=rmse(y, hc["pred"]),
-            w_speed=wsol["w_speed"],
-            w_batch=wsol["w_batch"],
-            t_speed_train=self._train_walls.get((sid, w), 0.0),
-            t_batch_infer=bmsg.payload["wall_s"],
-            t_speed_infer=smsg.payload["wall_s"],
-            t_hybrid_infer=hc.wall_s + t_w,
-            t_weight_solve=t_w,
-        )
-        self._records[(sid, w)] = rec
-        hy_site = self._module_site("hybrid_inference", sid)
-        self._schedule(
-            "hybrid_inference", wsol.wall_s + hc.wall_s, comm,
-            lambda: self.bus.publish(
-                stream_topic(T_HYBRID, sid),
-                {"stream": sid, "window": w, "rmse_hybrid": rec.rmse_hybrid,
-                 "w_speed": rec.w_speed},
-                _nbytes(hc["pred"]), hy_site),
-            site_name=hy_site)
+        with span("executor.on_part"):
+            st = self.stages.single
+            state = self._fleet.state(sid)
+            bmsg, smsg = parts["batch"], parts["speed"]
+            comm = max(m.deliver_time - m.publish_time
+                       for m in parts.values())
+            wsol = st.weight_solve(prev_preds=state.prev_preds,
+                                   prev_y=state.prev_y)
+            t_w = (wsol.wall_s if st.weight_solve.is_dynamic
+                   and state.prev_preds is not None else 0.0)
+            hc = st.hybrid_combine(
+                pred_speed=smsg.payload["pred"],
+                pred_batch=bmsg.payload["pred"],
+                w_speed=wsol["w_speed"], w_batch=wsol["w_batch"])
+            y = self._ys[(sid, w)]
+            rec = WindowRecord(
+                window=w,
+                rmse_batch=rmse(y, bmsg.payload["pred"]),
+                rmse_speed=rmse(y, smsg.payload["pred"]),
+                rmse_hybrid=rmse(y, hc["pred"]),
+                w_speed=wsol["w_speed"],
+                w_batch=wsol["w_batch"],
+                t_speed_train=self._train_walls.get((sid, w), 0.0),
+                t_batch_infer=bmsg.payload["wall_s"],
+                t_speed_infer=smsg.payload["wall_s"],
+                t_hybrid_infer=hc.wall_s + t_w,
+                t_weight_solve=t_w,
+            )
+            self._records[(sid, w)] = rec
+            hy_site = self._module_site("hybrid_inference", sid)
+            self._schedule(
+                "hybrid_inference", wsol.wall_s + hc.wall_s, comm,
+                lambda: self.bus.publish(
+                    stream_topic(T_HYBRID, sid),
+                    {"stream": sid, "window": w,
+                     "rmse_hybrid": rec.rmse_hybrid, "w_speed": rec.w_speed},
+                    _nbytes(hc["pred"]), hy_site),
+                site_name=hy_site)
 
     def _on_train(self, msg: Message) -> None:
         w = msg.payload["window"]
@@ -1403,74 +1412,78 @@ class FleetBusExecutor(_BusRuntime):
     def _dispatch_train(self, w: int, pend: Dict[StreamId, Message]) -> None:
         # the window's arrived streams are at the training site: one
         # drift-gated, stream-count-bucketed fleet dispatch
-        comm = max(m.deliver_time - m.publish_time for m in pend.values())
-        if not self._train_fits_site(comm):
-            return
-        train_ids = []
-        for s in self.ids:
-            if s not in pend:
-                continue
-            fire = _gate_decision(
-                self.gate, s, pend[s].payload["y"],
-                must=self._fleet.state(s).speed_params is None)
-            self._retrain_log[s].append(fire)
-            if fire:
-                train_ids.append(s)
-                if self.batch_refresh is not None:
-                    self.batch_refresh.archive(
-                        s, {"x": pend[s].payload["x"],
-                            "y": pend[s].payload["y"]})
-        self._maybe_refresh(w)
-        if not train_ids:
-            return
-        out = self.stages.speed_training(
-            fleet_data={s: {"x": pend[s].payload["x"],
-                            "y": pend[s].payload["y"]} for s in train_ids},
-            batch_params={s: self._bp[s] for s in train_ids},
-            keys={s: self._keys[s][w] for s in train_ids})
-        for s in train_ids:
-            # the shared fleet dispatch's wall, charged only to the streams
-            # that actually trained — a gate-skipped stream's window record
-            # keeps t_speed_train = 0
-            self._train_walls[(s, w)] = out["train_wall_s"]
-            if (s, w) in self._records:
-                self._records[(s, w)].t_speed_train = out["train_wall_s"]
+        with span("executor.dispatch_train"):
+            comm = max(m.deliver_time - m.publish_time
+                       for m in pend.values())
+            if not self._train_fits_site(comm):
+                return
+            train_ids = []
+            for s in self.ids:
+                if s not in pend:
+                    continue
+                fire = _gate_decision(
+                    self.gate, s, pend[s].payload["y"],
+                    must=self._fleet.state(s).speed_params is None)
+                self._retrain_log[s].append(fire)
+                if fire:
+                    train_ids.append(s)
+                    if self.batch_refresh is not None:
+                        self.batch_refresh.archive(
+                            s, {"x": pend[s].payload["x"],
+                                "y": pend[s].payload["y"]})
+            self._maybe_refresh(w)
+            if not train_ids:
+                return
+            out = self.stages.speed_training(
+                fleet_data={s: {"x": pend[s].payload["x"],
+                                "y": pend[s].payload["y"]}
+                            for s in train_ids},
+                batch_params={s: self._bp[s] for s in train_ids},
+                keys={s: self._keys[s][w] for s in train_ids})
+            for s in train_ids:
+                # the shared fleet dispatch's wall, charged only to the
+                # streams that actually trained — a gate-skipped stream's
+                # window record keeps t_speed_train = 0
+                self._train_walls[(s, w)] = out["train_wall_s"]
+                if (s, w) in self._records:
+                    self._records[(s, w)].t_speed_train = out["train_wall_s"]
 
         def publish_models():
-            from repro.runtime.faults import tree_checksum
+            with span("executor.publish_models"):
+                from repro.runtime.faults import tree_checksum
 
-            pubs = [out["fleet"][s]["params"] for s in train_ids]
-            if self.quantized_sync:
-                # the publish boundary: the bucket's stacked fit output
-                # materializes and quantizes in one batched pass
-                # (``quantize_fleet`` — one device_get + one vectorized
-                # int8 pass per stream bucket, not S per-stream chains),
-                # the per-stream model topics carry the real int8 byte
-                # counts, and the edge then serves the whole fleet through
-                # the batched int8 kernel
-                from repro.serving.quantize import quantize_fleet
+                pubs = [out["fleet"][s]["params"] for s in train_ids]
+                if self.quantized_sync:
+                    # the publish boundary: the bucket's stacked fit output
+                    # materializes and quantizes in one batched pass
+                    # (``quantize_fleet`` — one device_get + one vectorized
+                    # int8 pass per stream bucket, not S per-stream chains),
+                    # the per-stream model topics carry the real int8 byte
+                    # counts, and the edge then serves the whole fleet through
+                    # the batched int8 kernel
+                    from repro.serving.quantize import quantize_fleet
 
-                pubs = quantize_fleet(pubs, min_size=self.quant_min_size)
-            hp = self.health_plane
-            for s, params_pub in zip(train_ids, pubs):
-                o = out["fleet"][s]
-                payload = {"stream": s, "window": w, "params": params_pub,
-                           "eval_preds": o["eval_preds"],
-                           "eval_y": o["eval_y"],
-                           "checksum": tree_checksum(params_pub)}
-                if hp is not None and hp.sync_key is not None:
-                    # authenticated sync: the crc32 above catches damage in
-                    # transit, the HMAC catches tampering — a forger can
-                    # recompute the checksum but not the keyed signature
-                    from repro.runtime.health import sign_tree
+                    pubs = quantize_fleet(pubs, min_size=self.quant_min_size)
+                hp = self.health_plane
+                for s, params_pub in zip(train_ids, pubs):
+                    o = out["fleet"][s]
+                    payload = {"stream": s, "window": w, "params": params_pub,
+                               "eval_preds": o["eval_preds"],
+                               "eval_y": o["eval_y"],
+                               "checksum": tree_checksum(params_pub)}
+                    if hp is not None and hp.sync_key is not None:
+                        # authenticated sync: the crc32 above catches damage in
+                        # transit, the HMAC catches tampering — a forger can
+                        # recompute the checksum but not the keyed signature
+                        from repro.runtime.health import sign_tree
 
-                    payload["sig"] = sign_tree(params_pub, hp.sync_key)
-                nbytes = _nbytes(params_pub)
-                # keep the last publish so a corruption-triggered re-request
-                # can re-send without retraining
-                self._last_model_pub[s] = (payload, nbytes)
-                self.bus.publish(stream_topic(T_MODEL, s), payload, nbytes,
-                                 self.dep.site_of("speed_training"))
+                        payload["sig"] = sign_tree(params_pub, hp.sync_key)
+                    nbytes = _nbytes(params_pub)
+                    # keep the last publish so a corruption-triggered
+                    # re-request can re-send without retraining
+                    self._last_model_pub[s] = (payload, nbytes)
+                    self.bus.publish(stream_topic(T_MODEL, s), payload, nbytes,
+                                     self.dep.site_of("speed_training"))
 
         self._schedule("speed_training", out.wall_s, comm, publish_models)
 

@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.serving.batching import BatchScheduler
+from repro.tracing import span
 
 QUERY_KINDS = ("point", "horizon", "whatif")
 
@@ -167,24 +168,25 @@ class QueryPlane:
         A queue-head query whose stream has no context yet blocks admission
         — strict FIFO, no reordering — until its stream's first window
         lands."""
-        admitted = []
-        for i, s in enumerate(self.sched.slots):
-            if not s.free or not self.sched.queue:
-                continue
-            q = self.sched.queue[0]
-            if q.stream not in self._ctx:
-                break
-            self.sched.queue.popleft()
-            s.request = q
-            s.pos = q.prefill_len
-            q.admitted_at = now
-            ctx = np.array(self._ctx[q.stream], copy=True)
-            if q.kind == "whatif":
-                ctx = ctx * q.perturb_scale + q.perturb_offset
-            q.ctx = ctx
-            q.context_window = self._ctx_window[q.stream]
-            admitted.append(i)
-        return admitted
+        with span("plane.admit"):
+            admitted = []
+            for i, s in enumerate(self.sched.slots):
+                if not s.free or not self.sched.queue:
+                    continue
+                q = self.sched.queue[0]
+                if q.stream not in self._ctx:
+                    break
+                self.sched.queue.popleft()
+                s.request = q
+                s.pos = q.prefill_len
+                q.admitted_at = now
+                ctx = np.array(self._ctx[q.stream], copy=True)
+                if q.kind == "whatif":
+                    ctx = ctx * q.perturb_scale + q.perturb_offset
+                q.ctx = ctx
+                q.context_window = self._ctx_window[q.stream]
+                admitted.append(i)
+            return admitted
 
     def build_batch(self) -> Optional[Tuple[Dict[str, List[ForecastQuery]],
                                             List[np.ndarray]]]:
@@ -193,23 +195,24 @@ class QueryPlane:
         contribute a zero-row batch, so the dispatch shape stays one
         (stream bucket, shape bucket) entry.  None when no slot is
         active."""
-        by_stream: Dict[str, List[ForecastQuery]] = {sid: []
-                                                     for sid in self.ids}
-        ref = None
-        for s in self.sched.slots:
-            if s.request is not None:
-                by_stream[s.request.stream].append(s.request)
-                ref = s.request.ctx
-        if ref is None:
-            return None
-        xs = []
-        for sid in self.ids:
-            qs = by_stream[sid]
-            if qs:
-                xs.append(np.stack([q.ctx for q in qs]))
-            else:
-                xs.append(np.zeros((0,) + ref.shape, ref.dtype))
-        return by_stream, xs
+        with span("plane.build_batch"):
+            by_stream: Dict[str, List[ForecastQuery]] = {sid: []
+                                                         for sid in self.ids}
+            ref = None
+            for s in self.sched.slots:
+                if s.request is not None:
+                    by_stream[s.request.stream].append(s.request)
+                    ref = s.request.ctx
+            if ref is None:
+                return None
+            xs = []
+            for sid in self.ids:
+                qs = by_stream[sid]
+                if qs:
+                    xs.append(np.stack([q.ctx for q in qs]))
+                else:
+                    xs.append(np.zeros((0,) + ref.shape, ref.dtype))
+            return by_stream, xs
 
     def apply(self, by_stream: Dict[str, List[ForecastQuery]],
               preds: Sequence[np.ndarray],
@@ -221,23 +224,26 @@ class QueryPlane:
         context: next row = last row with the target column replaced by the
         prediction, window shifted by one.  ``fallback[sid]`` stamps the
         stream's answers as served from the batch-model fallback."""
-        answered = []
-        for sid, pred in zip(self.ids, preds):
-            for j, q in enumerate(by_stream[sid]):
-                p = float(np.asarray(pred[j]).reshape(-1)[0])
-                q.answer.append(p)
-                q.model_window = model_windows.get(sid, -1)
-                if fallback is not None and fallback.get(sid, False):
-                    q.served_fallback = True
-                if not q.done:
-                    nxt = np.array(q.ctx[-1], copy=True)
-                    nxt[self.target_col] = p
-                    q.ctx = np.concatenate([q.ctx[1:], nxt[None]], axis=0)
-                answered.append(q)
-        return answered
+        with span("plane.apply"):
+            answered = []
+            for sid, pred in zip(self.ids, preds):
+                for j, q in enumerate(by_stream[sid]):
+                    p = float(np.asarray(pred[j]).reshape(-1)[0])
+                    q.answer.append(p)
+                    q.model_window = model_windows.get(sid, -1)
+                    if fallback is not None and fallback.get(sid, False):
+                        q.served_fallback = True
+                    if not q.done:
+                        nxt = np.array(q.ctx[-1], copy=True)
+                        nxt[self.target_col] = p
+                        q.ctx = np.concatenate([q.ctx[1:], nxt[None]],
+                                               axis=0)
+                    answered.append(q)
+            return answered
 
     def retire(self, now: float) -> List[ForecastQuery]:
-        return self.sched.retire_finished(now)
+        with span("plane.retire"):
+            return self.sched.retire_finished(now)
 
     @property
     def busy(self) -> bool:

@@ -95,6 +95,7 @@ from repro.distributed.sharding import (
     stream_sharding,
 )
 from repro.models.model import Model
+from repro.tracing import span
 from repro.training.optimizer import Optimizer, adamw
 from repro.training.train_loop import make_train_step
 
@@ -800,37 +801,37 @@ class FleetForecaster:
                    out: List[Optional[Params]]) -> np.ndarray:
         s = len(idxs)
         sb = bucket_streams(s)
-        bufs = self._train_staging(sb, nb, datas[idxs[0]], keys[idxs[0]])
-        for j, i in enumerate(idxs):
-            d = datas[i]
-            n = len(next(iter(d.values())))
-            for k, v in d.items():
-                bufs[k][j, :n] = np.asarray(v)
-                bufs[k][j, n:] = 0
-            bufs["mask"][j, :n] = 1.0
-            bufs["mask"][j, n:] = 0.0
-            bufs["k0"][j] = np.asarray(keys[i])
-        for k in datas[idxs[0]]:
-            # stream-axis padding: zero data + all-zero validity mask, so
-            # the slot's loss/grad are exactly zero (any key gives a fine
-            # inert init; fold_in keeps it deterministic)
-            bufs[k][s:] = 0
-        bufs["mask"][s:] = 0.0
-        bufs["k0"][s:] = np.asarray(keys[idxs[0]])
-        bufs["fid"][:s] = 0
-        bufs["fid"][s:] = np.arange(1, sb - s + 1, dtype=np.int32)
-        # one batched dispatch derives every stream's (init, perm) keys —
-        # the same split/fold_in chain the sequential path runs per stream
-        ik_d, pk_d = self._key_fn(sb)(bufs["k0"], bufs["fid"])
-        padded0 = {k: bufs[k][0] for k in list(datas[idxs[0]]) + ["mask"]}
-        self._check_mask_honored(datas[idxs[0]], padded0, nb, ik_d)
-        carry = self._opt_carry.pop((sb, nb), None)
-        if carry is None:
-            carry = self._carry_init_fn(sb)(ik_d)
+        with span("fleet.fit.stage"):
+            bufs = self._train_staging(sb, nb, datas[idxs[0]], keys[idxs[0]])
+            for j, i in enumerate(idxs):
+                d = datas[i]
+                n = len(next(iter(d.values())))
+                for k, v in d.items():
+                    bufs[k][j, :n] = np.asarray(v)
+                    bufs[k][j, n:] = 0
+                bufs["mask"][j, :n] = 1.0
+                bufs["mask"][j, n:] = 0.0
+                bufs["k0"][j] = np.asarray(keys[i])
+            for k in datas[idxs[0]]:
+                # stream-axis padding: zero data + all-zero validity mask, so
+                # the slot's loss/grad are exactly zero (any key gives a fine
+                # inert init; fold_in keeps it deterministic)
+                bufs[k][s:] = 0
+            bufs["mask"][s:] = 0.0
+            bufs["k0"][s:] = np.asarray(keys[idxs[0]])
+            bufs["fid"][:s] = 0
+            bufs["fid"][s:] = np.arange(1, sb - s + 1, dtype=np.int32)
+            # one batched dispatch derives every stream's (init, perm) keys —
+            # the same split/fold_in chain the sequential path runs per stream
+            ik_d, pk_d = self._key_fn(sb)(bufs["k0"], bufs["fid"])
+            padded0 = {k: bufs[k][0] for k in list(datas[idxs[0]]) + ["mask"]}
+            self._check_mask_honored(datas[idxs[0]], padded0, nb, ik_d)
+            carry = self._opt_carry.pop((sb, nb), None)
+            if carry is None:
+                carry = self._carry_init_fn(sb)(ik_d)
+            x, y, mask = (self._put(bufs[k], sb) for k in ("x", "y", "mask"))
         params_S, opt_S, losses_S = self.fleet_fit_fn(sb, nb)(
-            carry, ik_d, pk_d,
-            self._put(bufs["x"], sb), self._put(bufs["y"], sb),
-            self._put(bufs["mask"], sb))
+            carry, ik_d, pk_d, x, y, mask)
         self._opt_carry[(sb, nb)] = opt_S
         jax.block_until_ready(params_S)
         self.train_dispatches += 1
@@ -929,20 +930,22 @@ class FleetForecaster:
         ns = [x.shape[0] for x in xs]
         nb = _next_pow2(max(max(ns), 1))
         sb = bucket_streams(S)
-        stacked, on_mesh = self._stack_fleet_params(params_seq, sb)
-        key = (sb, nb) + xs[0].shape[1:] + (xs[0].dtype.str,)
-        buf, allocated = _staging_buffer(
-            self._predict_bufs, key, (sb, nb) + xs[0].shape[1:],
-            xs[0].dtype)
-        self._staging_allocs += allocated
-        for j, x in enumerate(xs):
-            np.copyto(buf[j, :ns[j]], x)
-            buf[j, ns[j]:] = 0  # only the padding tail, not the whole buffer
-        buf[S:] = 0  # padded stream slots
-        x_dev = self._put(buf, sb) if on_mesh else jnp.asarray(buf)
+        with span("fleet.predict.stage"):
+            stacked, on_mesh = self._stack_fleet_params(params_seq, sb)
+            key = (sb, nb) + xs[0].shape[1:] + (xs[0].dtype.str,)
+            buf, allocated = _staging_buffer(
+                self._predict_bufs, key, (sb, nb) + xs[0].shape[1:],
+                xs[0].dtype)
+            self._staging_allocs += allocated
+            for j, x in enumerate(xs):
+                np.copyto(buf[j, :ns[j]], x)
+                buf[j, ns[j]:] = 0  # only the padding tail
+            buf[S:] = 0  # padded stream slots
+            x_dev = self._put(buf, sb) if on_mesh else jnp.asarray(buf)
         preds = self.predict_fleet_fn(sb)(stacked, x_dev)
         self.predict_dispatches += 1
-        preds = np.asarray(preds)
+        with span("fleet.predict.wait"):
+            preds = np.asarray(preds)
         return [preds[j, :ns[j]] for j in range(S)]
 
     def _check_mask_honored(self, data: Dict[str, np.ndarray],
