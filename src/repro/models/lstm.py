@@ -17,6 +17,11 @@ from repro.models import nn
 
 Params = Dict[str, Any]
 
+# Lags up to this unroll fully (no loop, and no per-step rewrite of a stacked
+# residual buffer in the gradient); longer ones keep the rolled scan, whose
+# compile time does not grow with the lag.
+UNROLL_MAX_LAG = 16
+
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     c = cfg.lstm
@@ -134,7 +139,9 @@ def forward(cfg: ModelConfig, p: Params, x: jax.Array,
             h, cc = cell_step(p["lstm"], x_t, h, cc)
             return (h, cc), None
 
-        (h, _), _ = jax.lax.scan(step, (h0, c0), x.transpose(1, 0, 2))
+        T = x.shape[1]
+        (h, _), _ = jax.lax.scan(step, (h0, c0), x.transpose(1, 0, 2),
+                                 unroll=T if T <= UNROLL_MAX_LAG else 1)
     d = jax.nn.relu(h @ p["dense"]["dense_w"] + p["dense"]["dense_b"])
     return d @ p["head"]["head_w"] + p["head"]["head_b"]
 

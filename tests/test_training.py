@@ -1,5 +1,6 @@
 """Optimizer, train loop and checkpoint tests."""
 import os
+import re
 import tempfile
 
 import jax
@@ -110,3 +111,57 @@ def test_train_step_is_jittable_and_deterministic():
     p1, s1, m1 = step(params, opt.init(params), batch)
     p2, s2, m2 = step(params, opt.init(params), batch)
     assert float(m1["loss"]) == float(m2["loss"])
+
+
+def _lstm_paper_batch(lag, seed=0):
+    cfg = get_config("lstm-paper")
+    rng = np.random.default_rng(seed)
+    x = rng.normal(0, 1, (64, lag, cfg.lstm.n_features)).astype(np.float32)
+    y = x[:, :, :1].mean(axis=1).astype(np.float32)
+    return cfg, {"x": jnp.asarray(x), "y": jnp.asarray(y)}
+
+
+@pytest.mark.parametrize("lag, rolled", [(5, False), (32, True)])
+def test_lstm_grad_unrolls_short_lags(lag, rolled):
+    """The gradient of a short-lag fit is straight-line code: no device
+    loop and no per-step rewrite of a stacked residual buffer.  A lag past
+    ``UNROLL_MAX_LAG`` keeps the rolled scan."""
+    from repro.models import lstm
+
+    assert (lag > lstm.UNROLL_MAX_LAG) == rolled
+    cfg, batch = _lstm_paper_batch(lag)
+    params = lstm.init_params(cfg, jax.random.PRNGKey(0))
+    grad = jax.grad(lambda p: lstm.loss_fn(cfg, p, batch)[0])
+    hlo = jax.jit(grad).lower(params).compile().as_text()
+    has_loop = re.search(r"\bwhile\(", hlo) is not None
+    assert has_loop == rolled
+    if not rolled:
+        assert "dynamic-update-slice" not in hlo
+
+
+def test_lstm_unrolled_matches_rolled_scan(monkeypatch):
+    """For lag 5 the unrolled recurrence computes what the rolled scan over
+    the same ``cell_step`` computes: forward outputs and every parameter
+    gradient agree to float32 rounding.  A gradient leaf is compared by its
+    norm: a near-zero entry left by cancellation differs from its rolled
+    twin by a rounding of the leaf's scale, not of its own."""
+    from repro.models import lstm
+
+    cfg, batch = _lstm_paper_batch(5, seed=1)
+    params = lstm.init_params(cfg, jax.random.PRNGKey(1))
+
+    def run():
+        pred = jax.jit(lambda p: lstm.forward(cfg, p, batch["x"]))(params)
+        grads = jax.jit(jax.grad(
+            lambda p: lstm.loss_fn(cfg, p, batch)[0]))(params)
+        return pred, grads
+
+    pred, grads = run()
+    monkeypatch.setattr(lstm, "UNROLL_MAX_LAG", 0)
+    pred_r, grads_r = run()
+    np.testing.assert_allclose(np.asarray(pred), np.asarray(pred_r),
+                               rtol=1e-6)
+    for g, g_r in zip(jax.tree_util.tree_leaves(grads),
+                      jax.tree_util.tree_leaves(grads_r)):
+        g, g_r = np.asarray(g), np.asarray(g_r)
+        assert np.linalg.norm(g - g_r) <= 1e-6 * np.linalg.norm(g_r)
