@@ -19,6 +19,7 @@ from repro.configs.base import (
     MoEConfig,
     RWKVConfig,
     SSMConfig,
+    TFTConfig,
     shape_applicable,
 )
 
@@ -33,6 +34,7 @@ from repro.configs.rwkv6_3b import CONFIG as _rwkv6
 from repro.configs.zamba2_1_2b import CONFIG as _zamba2
 from repro.configs.seamless_m4t_medium import CONFIG as _seamless
 from repro.configs.lstm_paper import CONFIG as _lstm_paper
+from repro.configs.tft_electricity import CONFIG as _tft_electricity
 
 # the ten assigned architectures, in assignment order
 ASSIGNED: List[ModelConfig] = [
@@ -50,6 +52,7 @@ ASSIGNED: List[ModelConfig] = [
 
 REGISTRY: Dict[str, ModelConfig] = {c.name: c for c in ASSIGNED}
 REGISTRY[_lstm_paper.name] = _lstm_paper
+REGISTRY[_tft_electricity.name] = _tft_electricity
 
 
 def get_config(name: str) -> ModelConfig:
@@ -82,6 +85,7 @@ __all__ = [
     "EncDecConfig",
     "FrontendStub",
     "LSTMConfig",
+    "TFTConfig",
     "InputShape",
     "HardwareModel",
     "FAMILIES",

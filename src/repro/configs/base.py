@@ -96,11 +96,44 @@ class LSTMConfig:
     out_dim: int = 1
 
 
+@dataclass(frozen=True)
+class TFTConfig:
+    """Temporal Fusion Transformer (Lim et al. 2021, arXiv:1912.09363):
+    one stream id as the static input, ``n_observed`` past-only reals
+    (column 0 the target) and ``n_known`` reals known for every step, a
+    ``lookback``-step encoder and a ``horizon``-step decoder, one LSTM layer
+    and one interpretable attention layer of ``n_heads`` heads, and one
+    output per quantile."""
+
+    hidden: int = 160
+    n_heads: int = 4
+    lookback: int = 168
+    horizon: int = 24
+    n_observed: int = 5
+    n_known: int = 3
+    n_ids: int = 8  # rows of the stream-id embedding
+    quantiles: Tuple[float, ...] = (0.1, 0.5, 0.9)
+    dropout: float = 0.1
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden // self.n_heads
+
+    @property
+    def steps(self) -> int:
+        return self.lookback + self.horizon
+
+    @property
+    def n_channels(self) -> int:
+        """Columns of a packed sample: observed, known, then the id."""
+        return self.n_observed + self.n_known + 1
+
+
 # ---------------------------------------------------------------------------
 # Model config
 # ---------------------------------------------------------------------------
 
-FAMILIES = ("dense", "moe", "ssm", "hybrid", "vlm", "audio", "lstm")
+FAMILIES = ("dense", "moe", "ssm", "hybrid", "vlm", "audio", "lstm", "tft")
 ATTENTION_KINDS = ("full", "swa", "none")
 MLP_VARIANTS = ("swiglu", "geglu", "squared_relu", "relu", "gelu")
 
@@ -133,6 +166,9 @@ class ModelConfig:
     encdec: Optional[EncDecConfig] = None
     frontend: Optional[FrontendStub] = None
     lstm: Optional[LSTMConfig] = None
+    tft: Optional[TFTConfig] = None
+    # global-norm gradient clip of the forecasters' AdamW
+    max_grad_norm: float = 1.0
     # implementation switches
     use_pallas: bool = False  # Pallas kernels (TPU target / interpret tests)
     remat: str = "none"  # "none" | "block" | "dots" — checkpoint policy
@@ -177,8 +213,9 @@ class ModelConfig:
 
     @property
     def has_decoder(self) -> bool:
-        """Everything here decodes (enc-dec includes a text decoder)."""
-        return self.family != "lstm"
+        """Everything here decodes (enc-dec includes a text decoder) but
+        the forecasters."""
+        return self.family not in ("lstm", "tft")
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

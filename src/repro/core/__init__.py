@@ -5,6 +5,8 @@ from repro.core.hybrid import (  # noqa: F401
     HybridRunResult,
     HybridStreamAnalytics,
     WindowRecord,
+    fleet_forecaster_for,
+    forecaster_for,
     lstm_fleet_forecaster,
     lstm_forecaster,
     pretrain_batch_model,

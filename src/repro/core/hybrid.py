@@ -11,7 +11,10 @@ Per time window t (paper Fig. 4):
 
 The orchestrator is generic over ``Forecaster`` so any model-zoo member can
 be the backbone; ``lstm_forecaster`` builds the paper's exact setup
-(batch: 50 epochs x bs 512; speed: 100 epochs x bs 64; lr 1e-3).
+(batch: 50 epochs x bs 512; speed: 100 epochs x bs 64; lr 1e-3), and
+``forecaster_for`` / ``fleet_forecaster_for`` build the forecaster a
+config's family names (the paper's LSTM, or the Temporal Fusion
+Transformer).
 
 The per-window work itself lives in ``repro.core.stages`` as discrete,
 individually-invokable pipeline stages; ``HybridStreamAnalytics.run`` is a
@@ -98,6 +101,51 @@ def lstm_fleet_forecaster(cfg: ModelConfig, *, epochs: int, batch_size: int,
     return FleetForecaster(
         model, epochs=epochs, batch_size=batch_size, lr=lr,
         predict_fn=lambda p, x: lstm_mod.predict(cfg, p, x), devices=devices)
+
+
+def _tft_parts(cfg: ModelConfig, lr: float):
+    from repro.models import tft as tft_mod
+    from repro.training.optimizer import adamw
+
+    return (get_model(cfg), lambda p, x: tft_mod.predict(cfg, p, x),
+            adamw(lr, clip_norm=cfg.max_grad_norm))
+
+
+def forecaster_for(cfg: ModelConfig, *, epochs: int, batch_size: int,
+                   lr: float = 1e-3) -> Forecaster:
+    """The single-stream forecaster of ``cfg``'s family: the paper's LSTM
+    (``lstm_forecaster``), or the Temporal Fusion Transformer (cold fits
+    only) with AdamW clipped at ``cfg.max_grad_norm``."""
+    if cfg.family == "lstm":
+        return lstm_forecaster(cfg, epochs=epochs, batch_size=batch_size,
+                               lr=lr)
+    if cfg.family == "tft":
+        eng = fleet_forecaster_for(cfg, epochs=epochs,
+                                   batch_size=batch_size, lr=lr)
+        return Forecaster(train=eng.train, predict=eng.predict, engine=eng)
+    raise ValueError(f"no forecaster for family {cfg.family!r}")
+
+
+def fleet_forecaster_for(cfg: ModelConfig, *, epochs: int, batch_size: int,
+                         lr: float = 1e-3, devices=None):
+    """The fleet forecaster of ``cfg``'s family (``lstm_fleet_forecaster``
+    for the LSTM)."""
+    if cfg.family == "lstm":
+        return lstm_fleet_forecaster(cfg, epochs=epochs,
+                                     batch_size=batch_size, lr=lr,
+                                     devices=devices)
+    if cfg.family == "tft":
+        from repro.training.compiled import FleetForecaster
+
+        model, predict_fn, opt = _tft_parts(cfg, lr)
+        # on a v5e the single-stream executable trained TFT's batch model
+        # to parameters that were not finite, where the fleet executable
+        # over a bucket of one matched the reference bit for bit: every
+        # TFT fit takes the fleet executable
+        return FleetForecaster(model, epochs=epochs, batch_size=batch_size,
+                               opt=opt, predict_fn=predict_fn,
+                               devices=devices, vmap_lone=True)
+    raise ValueError(f"no fleet forecaster for family {cfg.family!r}")
 
 
 @dataclass

@@ -63,6 +63,19 @@ def cell_step(p: Params, x_t: jax.Array, h: jax.Array, c: jax.Array):
     return h_new, c_new
 
 
+def run_cells(p: Params, xs: jax.Array, h0: jax.Array, c0: jax.Array, *,
+              sequence: bool = False, unroll: int = 1):
+    """``cell_step`` over time-major inputs ``xs`` (T, B, F) from the state
+    ``(h0, c0)``.  Returns the final ``(h, c)`` and, with ``sequence``, every
+    step's hidden state (T, B, H); else None in its place."""
+
+    def step(carry, x_t):
+        h, cc = cell_step(p, x_t, *carry)
+        return (h, cc), (h if sequence else None)
+
+    return jax.lax.scan(step, (h0, c0), xs, unroll=unroll)
+
+
 def _has_qtensor(p: Params) -> bool:
     from repro.serving.quantize import QTensor
 
@@ -133,15 +146,9 @@ def forward(cfg: ModelConfig, p: Params, x: jax.Array,
     else:
         h0 = jnp.zeros((B, c.hidden), x.dtype)
         c0 = jnp.zeros((B, c.hidden), x.dtype)
-
-        def step(carry, x_t):
-            h, cc = carry
-            h, cc = cell_step(p["lstm"], x_t, h, cc)
-            return (h, cc), None
-
         T = x.shape[1]
-        (h, _), _ = jax.lax.scan(step, (h0, c0), x.transpose(1, 0, 2),
-                                 unroll=T if T <= UNROLL_MAX_LAG else 1)
+        (h, _), _ = run_cells(p["lstm"], x.transpose(1, 0, 2), h0, c0,
+                              unroll=T if T <= UNROLL_MAX_LAG else 1)
     d = jax.nn.relu(h @ p["dense"]["dense_w"] + p["dense"]["dense_b"])
     return d @ p["head"]["head_w"] + p["head"]["head_b"]
 

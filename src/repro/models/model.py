@@ -35,6 +35,10 @@ class Model:
     prefill: Optional[Callable[..., Tuple[jax.Array, Params]]]
     decode_step: Optional[Callable[[Params, Batch, Params], Tuple[jax.Array, Params]]]
     init_cache: Optional[Callable[[int, int], Params]]
+    # a model that trains with dropout reads a per-step key, batch["rng"]
+    dropout: float = 0.0
+    # steps ahead that each prediction row answers at once
+    horizon: int = 1
 
 
 def get_model(cfg: ModelConfig) -> Model:
@@ -94,6 +98,19 @@ def get_model(cfg: ModelConfig) -> Model:
             decode_step=None,
             init_cache=None,
         )
+    if fam == "tft":
+        from repro.models import tft as m
+
+        return Model(
+            cfg=cfg,
+            init=lambda key: m.init_params(cfg, key),
+            loss_fn=lambda p, b: m.loss_fn(cfg, p, b),
+            prefill=None,
+            decode_step=None,
+            init_cache=None,
+            dropout=cfg.tft.dropout,
+            horizon=cfg.tft.horizon,
+        )
     raise ValueError(f"unknown family {fam!r}")
 
 
@@ -120,6 +137,14 @@ def input_specs(cfg: ModelConfig, shape: InputShape) -> Dict[str, Any]:
             "batch": {
                 "x": _sds((B, c.lag, c.n_features), cfg.dtype),
                 "y": _sds((B, c.out_dim), cfg.dtype),
+            }
+        }
+    if cfg.family == "tft":
+        c = cfg.tft
+        return {
+            "batch": {
+                "x": _sds((B, c.steps, c.n_channels), cfg.dtype),
+                "y": _sds((B, c.horizon), cfg.dtype),
             }
         }
 

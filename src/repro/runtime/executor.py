@@ -1096,7 +1096,8 @@ class FleetBusExecutor(_BusRuntime):
         self.e2e_s: Dict[StreamId, Dict[int, float]] = {sid: {} for sid in ids}
         self._ys: Dict[Tuple[StreamId, int], np.ndarray] = {}
         self._qplane: Optional[QueryPlane] = (
-            QueryPlane(ids, self.serve_slots)
+            QueryPlane(ids, self.serve_slots, horizon=getattr(
+                self.stages.speed_training.forecaster, "horizon", 1))
             if self._serving_enabled else None)
         self.queries: List[Any] = []
         self._query_lat: Dict[int, float] = {}

@@ -125,10 +125,18 @@ class QueryPlane:
     at the queue head), :meth:`build_batch` (per-stream slot contexts
     stacked into one fleet batch, aligned to the fleet order), and — after
     the one vmapped dispatch — :meth:`apply` (answers appended, horizon
-    contexts rolled) and :meth:`retire` (finished slots recycled)."""
+    contexts rolled) and :meth:`retire` (finished slots recycled).
+
+    ``horizon`` is how many steps the serving forecaster answers a row; the
+    plane refuses any but one."""
 
     def __init__(self, ids: Sequence[str], n_slots: int,
-                 target_col: int = 0):
+                 target_col: int = 0, horizon: int = 1):
+        if horizon != 1:
+            raise ValueError(
+                f"the query plane rolls a one-step forecaster's lag context "
+                f"a tick at a time; a forecaster that answers {horizon} "
+                f"steps at once is not served through it")
         self.ids = list(ids)
         self.sched = BatchScheduler(n_slots)
         self.target_col = target_col

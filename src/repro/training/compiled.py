@@ -251,12 +251,25 @@ def _staging_buffer(cache: Dict[Tuple, np.ndarray], key: Tuple,
     return buf, True
 
 
+# folded into a fit's permutation key to root its dropout keys, which
+# leaves the permutation keys as they are
+DROPOUT_FOLD = 0xD0
+
+
+def dropout_keys(rng: jax.Array, n_steps: int) -> jax.Array:
+    """The per-step dropout keys of a fit whose permutation key is
+    ``rng``: ``split(fold_in(rng, DROPOUT_FOLD), n_steps)``."""
+    return jax.random.split(jax.random.fold_in(rng, DROPOUT_FOLD), n_steps)
+
+
 def _make_epoch_scan(model: Model, opt: Optimizer, epochs: int,
                      batch_size: int, nb: int):
     """The pure epoch-scan fit body shared by the single-stream and fleet
     trainers: the whole fit (per-epoch permutations, minibatch gather,
     ``epochs x steps`` optimizer updates) is one ``lax.scan`` over a
-    device-resident pre-permuted epoch index tensor."""
+    device-resident pre-permuted epoch index tensor.  A model that declares
+    dropout also gets each step's key (``dropout_keys``) as
+    ``batch["rng"]``; any other model's fit is untouched by it."""
     steps = nb // batch_size
     train_step = make_train_step(model, opt)
 
@@ -265,15 +278,22 @@ def _make_epoch_scan(model: Model, opt: Optimizer, epochs: int,
             jax.random.split(rng, epochs))
         idx = perms.reshape(epochs * steps, batch_size)
 
-        def body(carry, ib):
+        dropout = model.dropout > 0
+
+        def body(carry, step):
             params, opt_state = carry
+            ib = step[0] if dropout else step
             batch = {"x": x[ib], "y": y[ib], "mask": mask[ib]}
+            if dropout:
+                batch["rng"] = step[1]
             params, opt_state, metrics = train_step(params, opt_state,
                                                     batch)
             return (params, opt_state), metrics["loss"]
 
+        steps_xs = ((idx, dropout_keys(rng, epochs * steps)) if dropout
+                    else idx)
         (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), idx)
+            body, (params, opt_state), steps_xs)
         return params, opt_state, losses
 
     return epoch_scan_fit
@@ -391,10 +411,11 @@ class CompiledForecaster:
         n = len(next(iter(data.values())))
         if n == nb or nb in self._mask_checked:
             return
-        plain, _ = self.model.loss_fn(
-            params, {k: jnp.asarray(v) for k, v in data.items()})
-        masked, _ = self.model.loss_fn(
-            params, {k: jnp.asarray(v) for k, v in padded.items()})
+        # one compiled loss, not one dispatch per operation of the model
+        loss = jax.jit(self.model.loss_fn)
+        plain, _ = loss(params, {k: jnp.asarray(v) for k, v in data.items()})
+        masked, _ = loss(params,
+                         {k: jnp.asarray(v) for k, v in padded.items()})
         if not np.allclose(np.asarray(plain), np.asarray(masked),
                            rtol=1e-4, atol=1e-6):
             raise ValueError(
@@ -524,7 +545,8 @@ class FleetForecaster:
       windows) always trains in exactly one;
     * a single-stream group (s == 1) delegates to the wrapped
       ``CompiledForecaster``, keeping the single-stream path byte-identical
-      to the pre-fleet code.
+      to the pre-fleet code; with ``vmap_lone`` it, and ``train``, run the
+      fleet executable over a stream bucket of one instead.
 
     ``train_dispatches`` counts fit-executable invocations (what
     ``benchmarks/bench_fleet.py`` asserts is one per window for a
@@ -552,7 +574,12 @@ class FleetForecaster:
         opt: Optional[Optimizer] = None,
         predict_fn: Optional[Callable[[Params, jax.Array], jax.Array]] = None,
         devices: Optional[Sequence[Any]] = None,
+        vmap_lone: bool = False,
     ):
+        # vmap_lone: a lone stream (a one-stream group, or ``train``) fits
+        # through the fleet executable over a stream bucket of one, not the
+        # wrapped single-stream trainer
+        self.vmap_lone = bool(vmap_lone)
         self.single = CompiledForecaster(
             model, epochs=epochs, batch_size=batch_size, lr=lr, opt=opt,
             predict_fn=predict_fn)
@@ -582,10 +609,20 @@ class FleetForecaster:
         # per-stream minibatch-loss trajectories of the last train_fleet call
         self.last_losses: Optional[List[Optional[np.ndarray]]] = None
 
+    @property
+    def horizon(self) -> int:
+        """Steps ahead that each prediction row answers at once (the query
+        plane serves one-step forecasters only)."""
+        return self.model.horizon
+
     # -- Forecaster protocol (the fleet's single-stream view) ----------------
 
     def train(self, data: Dict[str, np.ndarray], params: Optional[Params],
               key: jax.Array) -> Tuple[Params, float]:
+        if self.vmap_lone:
+            # a cold fit, as every fleet fit is
+            out, wall = self.train_fleet([data], [key])
+            return out[0], wall
         return self.single.train(data, params, key)
 
     def predict(self, params: Params, x: np.ndarray) -> np.ndarray:
@@ -767,7 +804,8 @@ class FleetForecaster:
         over the device-resident stacked fit output — semantically the
         per-stream trees (they flatten to them), materialized only when a
         consumer actually needs one; a single-stream group returns its
-        plain tree from the delegated single-stream path.
+        plain tree from the delegated single-stream path (a view too with
+        ``vmap_lone``).
 
         ``keys[i]`` plays exactly the role ``key`` plays in
         ``CompiledForecaster.train`` for stream ``i``."""
@@ -783,7 +821,7 @@ class FleetForecaster:
             groups.setdefault(bucket_examples(n, self.batch_size), []).append(i)
         losses: List[Optional[np.ndarray]] = [None] * len(datas)
         for nb, idxs in sorted(groups.items()):
-            if len(idxs) == 1:
+            if len(idxs) == 1 and not self.vmap_lone:
                 # byte-identical single-stream path (no vmap, no S padding)
                 i = idxs[0]
                 out[i], _ = self.single.train(datas[i], None, keys[i])

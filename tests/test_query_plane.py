@@ -306,3 +306,32 @@ def test_staleness_watchdog_serves_fallback_under_delayed_sync(fleet_setup,
         if not q.served_fallback and q.model_window >= 0:
             assert 0 <= q.context_window - q.model_window <= 1
     assert s["max_staleness"] <= 1
+
+
+def test_queryplane_refuses_a_multi_step_forecaster():
+    """The plane rolls a one-step forecaster's context a tick at a time; a
+    forecaster that answers a whole horizon at once (TFT's 24 steps) is
+    refused with a clear error, by the plane and by an executor asked to
+    serve through it, before anything compiles."""
+    import dataclasses
+
+    from repro.core import fleet_forecaster_for
+    from repro.core.windows import WindowPlan, WindowedStream
+
+    with pytest.raises(ValueError, match="one-step forecaster"):
+        QueryPlane(["a"], n_slots=2, horizon=24)
+    assert QueryPlane(["a"], n_slots=2, horizon=1).ids == ["a"]
+
+    pub = get_config("tft-electricity")
+    tcfg = pub.replace(tft=dataclasses.replace(
+        pub.tft, hidden=8, n_heads=2, lookback=6, horizon=3, n_ids=2))
+    fc = fleet_forecaster_for(tcfg, epochs=1, batch_size=4,
+                              devices=jax.devices()[:1])
+    assert fc.horizon == 3
+    series = np.zeros((40, 5), np.float32)
+    streams = {f"s{i}": WindowedStream(series, WindowPlan(
+        2, 20, 6, horizon=3, period=10.0, stream_id=i)) for i in range(2)}
+    ex = FleetBusExecutor(FleetStages.build(fc, mode="dynamic"),
+                          edge_cloud_integrated(), paper_topology(), qps=5.0)
+    with pytest.raises(ValueError, match="answers 3 steps at once"):
+        ex.run(streams, None, jax.random.PRNGKey(0), n_windows=2)
